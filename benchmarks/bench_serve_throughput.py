@@ -18,8 +18,9 @@ over that many devices, dp replicates engines behind the least-load
 router; ``--parallel-sweep`` crosses tp in {1,2,4} x dp in {1,2} and
 reports tokens/s plus per-device peak KV bytes per cell (the acceptance
 signal: per-device KV ~ 1/tp of the unsharded pool, one decode trace per
-replica throughout). Any of the three forces 8 virtual host devices
-before jax initializes; override via XLA_FLAGS.
+replica throughout). Under ``JAX_PLATFORMS=cpu`` any of the three
+forces 8 virtual host devices before jax initializes; override via
+XLA_FLAGS.
 
 ``--mixed-workload`` runs the chunked-prefill comparison instead: a
 long/short-interleaved prompt mix on the paged layout, each slot count
@@ -39,8 +40,9 @@ from __future__ import annotations
 import os
 import sys
 
-if any(a.startswith(("--tp", "--dp", "--parallel-sweep"))
-       for a in sys.argv):
+if os.environ.get("JAX_PLATFORMS") == "cpu" and \
+        any(a.startswith(("--tp", "--dp", "--parallel-sweep"))
+            for a in sys.argv):
     # must land before jax (imported below via repro.models) initializes
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
@@ -243,7 +245,8 @@ def main():
                     help="paged pool size (default: dense-capacity parity)")
     ap.add_argument("--tp", type=int, default=1,
                     help="tensor-parallel degree (shard the decode "
-                         "program + KV pool; forces 8 host devices)")
+                         "program + KV pool; forces 8 host devices "
+                         "under JAX_PLATFORMS=cpu)")
     ap.add_argument("--dp", type=int, default=1,
                     help="data-parallel replica count (least-load router)")
     ap.add_argument("--parallel-sweep", action="store_true",

@@ -11,6 +11,8 @@ def main() -> None:
                             bench_mfu_table1, bench_pipeline_bubble,
                             bench_roofline, bench_serve_throughput,
                             bench_table2_strategies, bench_table3_search)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     modules = [
         ("table1_mfu", bench_mfu_table1),
         ("table2_strategies", bench_table2_strategies),

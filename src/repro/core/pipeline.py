@@ -21,8 +21,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core.compat import shard_map
-
 
 # ------------------------------------------------------------ runtime (JAX)
 
@@ -83,7 +81,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
         local = jax.tree.map(lambda a: a[0], params)  # strip stage dim
         return gpipe_spmd(lambda xx: stage_fn(local, xx), mb, axis=axis)
 
-    out = shard_map(spmd, mesh=mesh, in_specs=in_specs,
+    out = jax.shard_map(spmd, mesh=mesh, in_specs=in_specs,
                     out_specs=P(*([None] * micro.ndim)),
                     check_vma=False)(stage_params, micro)
     return out.reshape(b, *x.shape[1:])

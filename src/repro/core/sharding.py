@@ -199,7 +199,7 @@ def cache_pspecs(cache: Any, strategy: Strategy, mesh: Mesh, batch: int):
 
     A cache carrying a ``"ptab"`` page table (the serve engine's paged
     layout) holds its decoder KV as one flat POOL
-    (L, n_pages, page_size, Hkv, D) shared by every slot instead of
+    (L, n_pages, Hkv, page_size, D) shared by every slot instead of
     per-slot rows: the pool is head-sharded over "model" (each device
     keeps Hkv/tp heads of EVERY page — intra-operator TP for serving)
     and never batch-sharded (pages have no batch dim; data-parallel
@@ -224,9 +224,9 @@ def cache_pspecs(cache: Any, strategy: Strategy, mesh: Mesh, batch: int):
             # the flat page pool: shard the kv-head axis over "model"
             # (fall back to replicated when GQA heads don't divide — the
             # page axis must stay whole, a gather index crosses it)
-            spec = P(None, None, None,
-                     rules["kv_heads"] if leaf.shape[3] % model_size == 0
-                     else None, None)
+            spec = P(None, None,
+                     rules["kv_heads"] if leaf.shape[2] % model_size == 0
+                     else None, None, None)
             return _divisible(leaf.shape, spec, mesh)
         if names[-1] in ("k", "v"):
             # Prefer KV-head sharding (Megatron); when GQA kv_heads don't

@@ -24,17 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
-
-
-def tpu_compiler_params(**kw):
-    """Compat shim: jax renamed ``TPUCompilerParams`` to
-    ``CompilerParams`` across releases; kernels must load under both."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kw)
 
 
 def pad_to_block(x, axis: int, block: int):
@@ -159,7 +151,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     def _kv_row(bh):
         return (bh // hq) * hkv + (bh % hq) // rep
 
-    from jax.experimental.pallas import tpu as pltpu
     out = pl.pallas_call(
         kernel,
         grid=(b * hq, nq, nk),
@@ -178,7 +169,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q,), jnp.float32),        # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),      # accumulator
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qr, kr, vr)

@@ -1,31 +1,43 @@
 """jit'd wrappers around the Pallas kernels — the public ops API.
 
-On this CPU box the kernels run with interpret=True (Pallas executes the
-kernel body in Python); on a real TPU the same calls compile to Mosaic.
-``INTERPRET`` flips automatically based on the backend.
+On a TPU the kernels compile to Mosaic; on the CPU they run with
+``interpret=True`` (Pallas executes the kernel body as XLA ops), which is
+how the CPU test suite covers them. :func:`interpret_mode` decides at
+call time, from the backend JAX actually runs on: a TPU never takes the
+interpreter, and any other backend is an error rather than a silent
+fallback.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.fused_mlp import fused_mlp as _fused_mlp
 from repro.kernels.paged_attention import paged_attention as _paged
 from repro.kernels.ssd_scan import ssd_scan as _ssd_scan
 
-INTERPRET = jax.default_backend() != "tpu"
 
-
-from functools import partial
-
-import jax.numpy as jnp
-import numpy as np
+def interpret_mode() -> bool:
+    """True on the CPU (interpret), False on a TPU (compile); raises for
+    any other backend, which has no Pallas TPU lowering."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels run compiled on a TPU or interpreted on the "
+        f"CPU; the {backend!r} backend has neither")
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_trainable(q, k, v, causal, window, block_q, block_k):
     return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=INTERPRET)
+                  block_k=block_k, interpret=interpret_mode())
 
 
 def _flash_fwd(q, k, v, causal, window, block_q, block_k):
@@ -87,7 +99,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos):
     gathered ``(B, P*page_size, ...)`` KV (kernels/paged_attention.py).
     Decode-only (no VJP): the serve engine's per-step program."""
     return _paged(q, k_pages, v_pages, page_table, pos,
-                  interpret=INTERPRET)
+                  interpret=interpret_mode())
 
 
 def fused_mlp(x, w_gate, w_up, w_down, *, block_m: int = 256,
@@ -95,9 +107,9 @@ def fused_mlp(x, w_gate, w_up, w_down, *, block_m: int = 256,
     orig = x.shape
     x2 = x.reshape(-1, orig[-1])
     y = _fused_mlp(x2, w_gate, w_up, w_down, block_m=block_m,
-                   block_f=block_f, interpret=INTERPRET)
+                   block_f=block_f, interpret=interpret_mode())
     return y.reshape(orig)
 
 
 def ssd_scan(x, dt, A, B, C, *, chunk: int = 128):
-    return _ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=INTERPRET)
+    return _ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=interpret_mode())

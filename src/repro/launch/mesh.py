@@ -1,9 +1,9 @@
 """Production meshes. Functions, not module constants — importing this
 module never touches jax device state.
 
-``make_mesh`` is the version-compatible constructor every caller should
-use: newer jax wants explicit ``axis_types`` (Auto) for the sharded-under-
-pjit meshes we build, older jax (< 0.5) has no ``AxisType`` at all.
+``make_mesh`` is the constructor every caller uses: every axis is
+``AxisType.Auto``, so GSPMD places whatever the sharding rules leave
+open.
 """
 from __future__ import annotations
 
@@ -14,16 +14,10 @@ import jax
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str], *,
               devices: Optional[Sequence] = None):
-    """jax.make_mesh across jax versions (with/without AxisType.Auto)."""
-    try:
-        axis_types = (jax.sharding.AxisType.Auto,) * len(axes)
-    except AttributeError:              # jax < 0.5: no AxisType, Auto implied
-        return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
-                         axis_types=axis_types)
-
-
-_mk = make_mesh                         # backwards-compatible alias
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

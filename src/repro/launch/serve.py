@@ -1,8 +1,13 @@
-"""Serving launcher: spin up the continuous-batching engine on a reduced
-config (through ``repro.api.Session`` — the session owns param init) and
-stream a synthetic request workload through it.
+"""Serving launcher: spin up the continuous-batching engine (through
+``repro.api.Session`` — the session owns param init) and stream a
+synthetic request workload through it.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b \
+By default the registry config is served at its published widths and in
+its own dtype, with random weights; ``--smoke`` serves the reduced
+float32 variant instead, which is what runs on a CPU:
+
+    PYTHONPATH=src python -m repro.launch.serve --arch minitron-4b
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --smoke \
         --requests 6 --max-new 12
 
 The engine defaults to the paged (block-table) KV cache wherever it is
@@ -24,9 +29,11 @@ self-drafted tokens per slot verified in the same dispatch
 Parallel serving (serve/parallel.py): ``--tp N`` shards the one-trace
 decode program over N devices (Megatron layout, head-sharded KV pool),
 ``--dp M`` replicates the engine M times behind a least-load router —
-``--tp 2 --dp 2`` needs 4 devices. On a CPU host the launcher forces 8
-virtual devices up front (before jax initializes) so both flags work out
-of the box; set XLA_FLAGS yourself to override.
+``--tp 2 --dp 2`` needs 4 devices. The session is built on that
+``(dp, tp)`` mesh, so the parameters are born sharded and never
+replicated onto every device first. Under ``JAX_PLATFORMS=cpu`` the
+launcher forces 8 virtual devices up front (before jax initializes) so
+both flags work on a CPU host; set XLA_FLAGS yourself to override.
 
 ONLINE mode (``--serve``) skips the synthetic batch and stands up the
 HTTP front-end (serve/server.py) on ``--port`` instead: ``POST
@@ -35,7 +42,7 @@ HTTP front-end (serve/server.py) on ``--port`` instead: ``POST
 preemption counters), ``GET /healthz``; ``--watchdog-timeout`` arms the
 stalled-step watchdog (diagnostic dump + cancel-and-requeue recovery).
 
-    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b \
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-14b --smoke \
         --serve --port 8000 --watchdog-timeout 30
     curl -s localhost:8000/generate -d '{"prompt": [1,2,3], "max_new": 8}'
     curl -s localhost:8000/metrics | grep serve_ttft
@@ -45,7 +52,8 @@ from __future__ import annotations
 import os
 import sys
 
-if any(a.startswith(("--tp", "--dp")) for a in sys.argv):
+if os.environ.get("JAX_PLATFORMS") == "cpu" and \
+        any(a.startswith(("--tp", "--dp")) for a in sys.argv):
     # must land before jax (imported below via repro.api) initializes
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=8")
@@ -53,15 +61,21 @@ if any(a.startswith(("--tp", "--dp")) for a in sys.argv):
 import argparse
 import time
 
+import jax
 import numpy as np
 
 from repro.api import Session
-from repro.configs import ARCH_NAMES, get_smoke
+from repro.configs import ARCH_NAMES, get_config, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_NAMES, default="qwen3-14b")
+    ap.add_argument("--arch", choices=ARCH_NAMES, default="minitron-4b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced float32 smoke variant of the "
+                         "arch (default: its published widths and dtype)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=12)
     ap.add_argument("--slots", type=int, default=2)
@@ -149,8 +163,13 @@ def main():
                          "ui.perfetto.dev or chrome://tracing")
     args = ap.parse_args()
 
-    cfg = get_smoke(args.arch).with_(dtype="float32")
-    session = Session(cfg)
+    enable_compile_cache()
+    cfg = get_smoke(args.arch).with_(dtype="float32") if args.smoke \
+        else get_config(args.arch)
+    n = args.dp * args.tp
+    mesh = make_mesh((args.dp, args.tp), ("data", "model"),
+                     devices=jax.devices()[:n])
+    session = Session(cfg, mesh=mesh)
     spec = None
     if args.spec_k > 0:
         from repro.serve.speculative import SpecConfig

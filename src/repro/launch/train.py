@@ -1,7 +1,8 @@
 """Training launcher: ``--arch`` x strategy on the local (or forced-count)
 device mesh, driven through the ``repro.api.Session`` facade. For the
 production 256/512-chip meshes use dryrun.py; this driver actually
-executes steps (reduced config by default, since the box is CPU).
+executes steps: the registry config at its published widths, or the
+reduced float32 variant with ``--smoke`` (what runs on a CPU).
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-14b \
         --steps 50 --smoke                        # reduced variant, runs
@@ -13,6 +14,7 @@ import argparse
 
 from repro.api import Session, Strategy, TrainConfig, plan
 from repro.configs import ARCH_NAMES, SHAPES, get_config, get_smoke
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -24,8 +26,8 @@ def main():
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--smoke", action="store_true",
-                    help="use the reduced per-arch smoke config (default on "
-                         "CPU; full configs are dry-run only)")
+                    help="train the reduced float32 per-arch smoke config "
+                         "(default: published widths in the config's dtype)")
     ap.add_argument("--plan", action="store_true",
                     help="print the planner's production-mesh strategy and "
                          "exit")
@@ -41,10 +43,12 @@ def main():
         print(f"{args.arch}: {p.summary()}")
         return
 
-    cfg = get_smoke(args.arch).with_(dtype="float32")
+    enable_compile_cache()
+    cfg = get_smoke(args.arch).with_(dtype="float32") if args.smoke \
+        else get_config(args.arch)
     strategy = Strategy(remat=False, microbatches=args.microbatches,
                         seq_parallel=args.seq_parallel, fsdp=args.fsdp,
-                        dtype="float32")
+                        dtype=cfg.dtype)
     session = Session(cfg, strategy, make_host_mesh(model=1))
     tc = TrainConfig(steps=args.steps, lr=args.lr, log_every=10,
                      checkpoint_every=args.steps if args.checkpoint_dir

@@ -30,7 +30,6 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import pspec as _pspec
-from repro.core.compat import shard_map
 
 
 def cp_available(cache_k) -> bool:
@@ -113,7 +112,7 @@ def cp_decode_attention(q, kv, k_new, v_new, pos, *, window: int = 0,
 
     spec_kv = P(batch_ax, axis, None, None)
     rep4 = P(batch_ax, None, None, None)
-    ctx, k2, v2 = shard_map(
+    ctx, k2, v2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep4, spec_kv, spec_kv, rep4, rep4, P()),
         out_specs=(rep4, spec_kv, spec_kv),

@@ -171,7 +171,7 @@ def decode_step(params, cache, token, pos, cfg, *,
     """
     x = params["tok_embed"][token].astype(jnp.dtype(cfg.dtype))
     paged = "ptab" in cache
-    w = (cache["ptab"].shape[1] * cache["kv"]["k"].shape[2] if paged
+    w = (cache["ptab"].shape[1] * cache["kv"]["k"].shape[3] if paged
          else cache["kv"]["k"].shape[2])
     pos = jnp.asarray(pos, jnp.int32)
     pe_table = sinusoid(w, cfg.d_model)

@@ -30,16 +30,21 @@ passes it to ``decode_step`` directly.
 Paged layout (vLLM-style block tables; arXiv:2309.06180)
 --------------------------------------------------------
 The third layout drops per-slot rows entirely: one flat POOL of
-fixed-size pages ``(layers, n_pages, page_size, Hkv, D)`` shared by every
+fixed-size pages ``(layers, n_pages, Hkv, page_size, D)`` shared by every
 slot, plus a per-slot page table ``ptab`` (slots, P) of pool indices that
 maps logical block ``p // page_size`` of slot ``b`` to a physical page.
 Token ``p`` of slot ``b`` therefore lives at
-``pool[ptab[b, p // page_size], p % page_size]``:
+``pool[ptab[b, p // page_size], :, p % page_size]``. The pool is
+HEAD-MAJOR — each page holds one contiguous ``(page_size, D)`` tile per
+KV head — because that is the block the TPU kernel
+(kernels/paged_attention.py) can fetch: a block's last two dims must be
+the pool's own or tile-aligned, and a token-major page would put the
+head axis second-to-last.
 
   * ``write_kv_paged`` scatters each row's decode token through the table
     at its own cursor — still ONE device program for the whole slot table;
-  * ``layers.paged_attention`` gathers ``pool[ptab[b]]`` so the gathered
-    axis IS the position axis, then masks to each row's live prefix —
+  * ``layers.paged_attention`` gathers ``pool[ptab[b]]`` into position
+    order (``gather_pages``), then masks to each row's live prefix —
     identical math to the dense path, so paged and dense decode are
     token-identical for row-independent (non-MoE) archs; MoE capacity
     routing couples slot rows either way, and the layouts feed inactive
@@ -61,7 +66,7 @@ duplicates a shared page's contents before a writer appends into it.
 
 Sharded (TP) pool layout: under a ("data", "model") mesh the pool keeps
 this exact shape but is partitioned on the KV-HEAD axis —
-``(L, n_pages, page_size, Hkv/tp, D)`` per device
+``(L, n_pages, Hkv/tp, page_size, D)`` per device
 (core/sharding.cache_pspecs) — so every device holds its head slice of
 EVERY page and each resident page costs 1/tp per device. Page ids stay
 global (the page/table axes are never sharded: a table lookup must
@@ -71,6 +76,7 @@ deals in page ids and table values.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -81,49 +87,71 @@ def init_kv(batch: int, length: int, n_kv: int, head_dim: int, dtype):
 
 def init_paged_kv(layers: int, n_pages: int, page_size: int, n_kv: int,
                   head_dim: int, dtype):
-    """Flat page pool shared by every slot. ``n_pages`` INCLUDES the null
-    page 0 (so a pool serving K usable pages has n_pages = K + 1)."""
-    shape = (layers, n_pages, page_size, n_kv, head_dim)
+    """Flat head-major page pool shared by every slot:
+    ``(layers, n_pages, n_kv, page_size, head_dim)``. ``n_pages`` INCLUDES
+    the null page 0 (so a pool serving K usable pages has n_pages = K + 1)."""
+    shape = (layers, n_pages, n_kv, page_size, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def write_kv_paged(cache, k_new, v_new, page_table, pos):
     """Scatter one decode token per slot through the page table.
 
-    cache leaves: (n_pages, page_size, Hkv, D) — ONE layer's pool (models
+    cache leaves: (n_pages, Hkv, page_size, D) — ONE layer's pool (models
     scan over the stacked layer axis). k_new/v_new: (B, 1, Hkv, D);
     page_table: (B, P) pool indices; pos: (B,) per-row cursors. Inactive
     slots resolve to the null page 0 (their table rows are zeroed and the
     block index is clipped), so the scatter is total — no masking branch,
     no retrace.
     """
-    page_size = cache["k"].shape[1]
+    page_size = cache["k"].shape[2]
     pos = jnp.asarray(pos)
     rows = jnp.arange(page_table.shape[0])
     blk = jnp.clip(pos // page_size, 0, page_table.shape[1] - 1)
     page = page_table[rows, blk]
     off = pos % page_size
     cache = dict(cache)
-    cache["k"] = cache["k"].at[page, off].set(k_new[:, 0])
-    cache["v"] = cache["v"].at[page, off].set(v_new[:, 0])
+    # (page, :, off) indexing puts the row axis first: (B, Hkv, D)
+    cache["k"] = cache["k"].at[page, :, off].set(k_new[:, 0])
+    cache["v"] = cache["v"].at[page, :, off].set(v_new[:, 0])
     return cache
+
+
+def scatter_prefill_pages(pool, kvs, pages, page_size: int):
+    """Write a freshly-prefilled per-request KV into its pool pages.
+
+    pool leaves: (L, n_pages, Hkv, page_size, D) — the engine's shared
+    head-major page pool. kvs leaves: (L, 1, S, Hkv, D) with S a
+    multiple of ``page_size`` (the prefill cache is sized to whole
+    pages). pages: (S // page_size,) pool indices — entries beyond the
+    slot's reservation are the null page 0, so bucket padding lands in
+    scratch instead of a neighbour's page.
+    """
+    def put(pool_leaf, kv_leaf):
+        l, _, s, h, d = kv_leaf.shape
+        tiles = kv_leaf.reshape(l, s // page_size, page_size, h, d)
+        return pool_leaf.at[:, pages].set(tiles.swapaxes(2, 3))
+
+    return jax.tree.map(put, pool, kvs)
 
 
 def copy_page(pool, src, dst):
     """Copy one physical page's contents, all layers at once — the device
     half of copy-on-write (the allocator swaps the table entry, this moves
-    the KV). pool leaves: (L, n_pages, page_size, Hkv, D); src/dst:
+    the KV). pool leaves: (L, n_pages, Hkv, page_size, D); src/dst:
     scalar page ids (traced values, so ONE program covers every copy)."""
     return {"k": pool["k"].at[:, dst].set(pool["k"][:, src]),
             "v": pool["v"].at[:, dst].set(pool["v"][:, src])}
 
 
 def gather_pages(pool, page_table):
-    """pool: (n_pages, page_size, ...); page_table: (B, P) -> contiguous
-    per-row KV (B, P * page_size, ...) in logical position order."""
+    """pool: (n_pages, Hkv, page_size, D); page_table: (B, P) ->
+    contiguous per-row KV (B, P * page_size, Hkv, D) in logical position
+    order."""
     b, p = page_table.shape
-    out = pool[page_table]
-    return out.reshape(b, p * pool.shape[1], *pool.shape[2:])
+    _, hkv, page_size, d = pool.shape
+    out = pool[page_table].swapaxes(2, 3)       # (B, P, page_size, Hkv, D)
+    return out.reshape(b, p * page_size, hkv, d)
 
 
 def ring_slot(pos, window: int):

@@ -93,9 +93,14 @@ def attention_full(q, k, v, *, causal: bool, window: int = 0,
     """
     b, s, hq, d = q.shape
     t, hkv = k.shape[1], k.shape[2]
-    k, v = _repeat_kv(k, hq // hkv), _repeat_kv(v, hq // hkv)
-    scores = jnp.einsum("bshd,bthd->bhst", q, k,
-                        preferred_element_type=jnp.float32)
+    rep = hq // hkv
+    # GQA without repeating K/V to Hq heads: query head g*rep + r reads
+    # kv head g (the _repeat_kv mapping), contracted group-wise, so the
+    # paged gather's (B, T, Hkv, D) K/V are never widened rep-fold
+    qg = q.reshape(b, s, hkv, rep, d)
+    scores = jnp.einsum("bsgrd,btgd->bgrst", qg, k,
+                        preferred_element_type=jnp.float32
+                        ).reshape(b, hq, s, t)
     scores *= 1.0 / np.sqrt(d)
     # per-row broadcasting: qpos (1|B, S), kpos (1|B, T) -> mask (1|B, S, T)
     qpos = jnp.asarray(q_offset).reshape(-1, 1) + jnp.arange(s)
@@ -113,7 +118,9 @@ def attention_full(q, k, v, *, causal: bool, window: int = 0,
     mask &= kpos[:, None, :] >= 0
     scores = jnp.where(mask[:, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhst,bthd->bshd", probs, v)
+    out = jnp.einsum("bgrst,btgd->bsgrd", probs.reshape(b, hkv, rep, s, t),
+                     v)
+    return out.reshape(b, s, hq, d)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, *,
@@ -121,7 +128,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     """Decode-time block-table attention over a paged KV pool (vLLM-style).
 
     q: (B, 1, Hq, D) — one fresh token per slot-table row.
-    k_pages/v_pages: (n_pages, page_size, Hkv, D) — the flat shared pool.
+    k_pages/v_pages: (n_pages, Hkv, page_size, D) — the flat shared
+    head-major pool (models/kvcache.py).
     page_table: (B, P) pool indices per row (0 = null page).
     pos: (B,) per-row cursors (tokens already in context, incl. this one's
     write — the query attends to positions [0, pos]).
@@ -147,7 +155,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     scalar-prefetched table — contiguous KV is never materialized and
     GQA heads fold in-kernel. Same masking (``kv_len = pos + 1``), same
     trace cadence (shapes depend only on the bucketed table width); on
-    CPU it runs in interpret mode (kernels/ops.INTERPRET).
+    CPU it runs in interpret mode (kernels/ops.interpret_mode).
 
     TP note: under a ("data", "model") mesh the pool is head-sharded
     over "model" (core/sharding.cache_pspecs) — both backends index the

@@ -86,7 +86,7 @@ standard orca/vllm outer loop). Prefill pads prompts to power-of-two
 buckets (serve/step.prefill_bucket) so XLA retraces at most
 log2(max_len) prefill shapes; paged prefill additionally rounds the
 bucket up to whole pages and scatters the fresh KV page-wise
-(serve/step.scatter_prefill_pages), skipping blocks the prefix cache
+(kvcache.scatter_prefill_pages), skipping blocks the prefix cache
 already holds. Sampling (greedy or temperature) runs on device inside
 the same jitted step (serve/sampling.py).
 
@@ -111,7 +111,7 @@ Passing a ("data", "model") mesh (+ the Strategy whose rules map logical
 axes onto it) runs the SAME one-trace prefill/decode programs sharded
 GSPMD-style across the mesh's ``tp`` devices: params take the Megatron
 §5.1 layout (core/sharding.param_pspecs), and the paged pool keeps its
-flat ``(L, n_pages, page_size, Hkv, D)`` shape but is HEAD-SHARDED over
+flat ``(L, n_pages, Hkv, page_size, D)`` shape but is HEAD-SHARDED over
 "model" — each device holds ``Hkv/tp`` heads of every page, so resident
 per-device KV is ~1/tp of the unsharded pool while the page axis stays
 whole (the block-table gather indexes it). The page table, cursors and
@@ -165,8 +165,7 @@ from repro.serve.paging import PageAllocator, pages_for
 from repro.serve.prefix import PrefixCache
 from repro.serve.sampling import sample_tokens
 from repro.serve.scheduler import FifoLeastProgress
-from repro.serve.step import (pack_token_budget, page_bucket,
-                              prefill_bucket, scatter_prefill_pages)
+from repro.serve.step import pack_token_budget, page_bucket, prefill_bucket
 from repro.serve.tracing import NULL_STEP, Tracer, chrome_trace, \
     export_chrome_trace
 
@@ -329,9 +328,17 @@ class ServeEngine:
             params = jax.device_put(
                 params, shd.param_shardings(params, self.strategy, mesh))
             self._ctx = lambda: sharding_rules(self.mesh, self._rules)
+            self._placement = NamedSharding(mesh, P())
         else:
             self.strategy = strategy
             self._ctx = nullcontext
+            # unsharded, but the params may sit on a one-device mesh (a
+            # Session's): keep the decode state and host inputs on that
+            # same mesh, or the first step's outputs come back with a
+            # sharding its inputs lacked and the step compiles twice
+            home = getattr(jax.tree.leaves(params)[0], "sharding", None)
+            self._placement = NamedSharding(home.mesh, P()) \
+                if isinstance(home, NamedSharding) else None
         self.cfg, self.params = cfg, params
         self.model = get_model(cfg)
         self.slots = slots
@@ -444,6 +451,8 @@ class ServeEngine:
                 jax.tree.map(lambda s: NamedSharding(mesh, s),
                              shd.cache_pspecs(self._cache, self.strategy,
                                               mesh, slots)))
+        elif self._placement is not None:
+            self._cache = jax.device_put(self._cache, self._placement)
         # bucketing: attention masks make right-padding exact for dense;
         # MoE capacity routing and the SSM recurrence are perturbed by pad
         # tokens (and enc-dec prefill gathers no last_pos), so those archs
@@ -510,9 +519,9 @@ class ServeEngine:
         the mesh when sharded) so jit sees one stable input sharding —
         uncommitted host arrays would leave the placement choice to the
         compiler."""
-        if self.mesh is None:
+        if self._placement is None:
             return jnp.asarray(x)
-        return jax.device_put(x, NamedSharding(self.mesh, P()))
+        return jax.device_put(x, self._placement)
 
     # ------------------------------------------------------- jitted steps
     def _decode_fn(self, params, cache, tokens, pos, active, rng):
@@ -560,8 +569,8 @@ class ServeEngine:
             elif key == "ptab":
                 out[key] = big
             elif key == "kv" and self.paged:
-                out[key] = scatter_prefill_pages(big, c1[key], pages,
-                                                 self.page_size)
+                out[key] = kvcache.scatter_prefill_pages(
+                    big, c1[key], pages, self.page_size)
             else:
                 out[key] = jax.tree.map(
                     lambda b, o: b.at[:, slot].set(o[:, 0]), big, c1[key])

@@ -1,6 +1,6 @@
 """Serving steps: prefill and single-token decode (the dry-run targets for
-prefill_32k / decode_32k / long_500k), prompt-length bucketing, the
-page-wise prefill scatter for the engine's paged KV layout, and the
+prefill_32k / decode_32k / long_500k), prompt-length and page-table
+bucketing, the mixed step's token-budget packing, and the
 greedy/sampled generate loop."""
 from __future__ import annotations
 
@@ -101,24 +101,6 @@ def pack_token_budget(budget: int, decode_rows, prefill_items):
         planned_end[it["slot"]] = it["cursor"] + take
         left -= take
     return allot
-
-
-def scatter_prefill_pages(pool, kvs, pages, page_size: int):
-    """Write a freshly-prefilled per-request KV into its pool pages.
-
-    pool leaves: (L, n_pages, page_size, Hkv, D) — the engine's shared
-    page pool. kvs leaves: (L, 1, S, Hkv, D) with S a multiple of
-    ``page_size`` (the prefill cache is sized to whole pages). pages:
-    (S // page_size,) pool indices — entries beyond the slot's reservation
-    are the null page 0, so bucket padding lands in scratch instead of a
-    neighbour's page.
-    """
-    def put(pool_leaf, kv_leaf):
-        l, _, s, h, d = kv_leaf.shape
-        tiles = kv_leaf.reshape(l, s // page_size, page_size, h, d)
-        return pool_leaf.at[:, pages].set(tiles)
-
-    return jax.tree.map(put, pool, kvs)
 
 
 def make_prefill_step(cfg, strategy: Strategy) -> Callable:

@@ -45,9 +45,24 @@ def init_sharded_params(cfg: ModelConfig, strategy: Strategy, mesh: Mesh,
                                    jax.random.key(seed)),
                     strategy, mesh)),
         )(jax.random.key(seed))
-    # jit dedups identical constants (e.g. the ln1/ln2 ones-vectors) into
-    # ONE buffer; donation would then see the same buffer twice. Copy.
-    return jax.tree.map(lambda x: x.copy(), params)
+    return _unalias(params)
+
+
+def _unalias(params):
+    """jit dedups identical constants (e.g. the ln1/ln2 ones-vectors) into
+    ONE buffer, and donation would then see the same buffer twice. Copy
+    only the leaves whose buffer an earlier leaf already holds: copying
+    every leaf while the originals are alive would double the peak."""
+    seen = set()
+
+    def one(x):
+        ptrs = {s.data.unsafe_buffer_pointer() for s in x.addressable_shards}
+        if ptrs & seen:
+            return x.copy()
+        seen.update(ptrs)
+        return x
+
+    return jax.tree.map(one, params)
 
 
 @dataclass

@@ -18,13 +18,6 @@ def _mesh(data, model):
     return make_mesh((data, model), ("data", "model"))
 
 
-# jax 0.4.x (no AxisType) on CPU orders the qwen3 reductions differently
-# between the sharded and reference programs; the resulting near-zero-grad
-# noise flips first-step adamw update signs (m_hat/sqrt(v_hat) -> +-1), a
-# +-lr param jump that is an optimizer artifact, not a sharding bug.
-OLD_JAX = not hasattr(jax.sharding, "AxisType")
-
-
 @pytest.mark.parametrize("arch", ["qwen3-14b", "olmoe-1b-7b", "mamba2-780m"])
 @pytest.mark.parametrize("strategy_kw", [
     dict(),                                  # Megatron baseline
@@ -32,9 +25,6 @@ OLD_JAX = not hasattr(jax.sharding, "AxisType")
     dict(fsdp=True),                         # +ZeRO-3
 ])
 def test_train_step_sharded_equals_reference(arch, strategy_kw):
-    if OLD_JAX and arch == "qwen3-14b":
-        pytest.xfail("jax 0.4.x CPU reduction order flips first-step adamw "
-                     "signs on near-zero qwen3 grads (see OLD_JAX note)")
     cfg = get_smoke(arch).with_(dtype="float32", moe_capacity_factor=16.0)
     mod = get_model(cfg)
     key = jax.random.key(0)

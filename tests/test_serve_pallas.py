@@ -67,13 +67,13 @@ def _serve(cfg, params, prompts, new, *, frames=None, mesh=None, slots=2,
 # ------------------------------------------------------- kernel parity
 
 def _rand_paged(rng, *, b, width, n_pages, page_size, hq, hkv, d):
-    """A pool + adversarial tables: page ids permuted and fragmented
-    (interleaved across rows, non-contiguous, nowhere in logical order),
-    page 0 reserved as the null page, per-row cursors landing mid-page
-    so the last page is ragged."""
-    kp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, d)),
+    """A head-major pool + adversarial tables: page ids permuted and
+    fragmented (interleaved across rows, non-contiguous, nowhere in
+    logical order), page 0 reserved as the null page, per-row cursors
+    landing mid-page so the last page is ragged."""
+    kp = jnp.asarray(rng.standard_normal((n_pages, hkv, page_size, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((n_pages, page_size, hkv, d)),
+    vp = jnp.asarray(rng.standard_normal((n_pages, hkv, page_size, d)),
                      jnp.float32)
     perm = rng.permutation(np.arange(1, n_pages))  # never the null page
     tab = np.zeros((b, width), np.int32)
@@ -118,8 +118,8 @@ def test_kernel_masks_null_pages_exactly():
     # ...and garbage INSIDE referenced pages past the cursor (ragged tail)
     for r in range(3):
         last = int(np.asarray(tab)[r, int(pos[r]) // 4])
-        kp = kp.at[last, int(pos[r]) % 4 + 1:].set(1e9)
-        vp = vp.at[last, int(pos[r]) % 4 + 1:].set(1e9)
+        kp = kp.at[last, :, int(pos[r]) % 4 + 1:].set(1e9)
+        vp = vp.at[last, :, int(pos[r]) % 4 + 1:].set(1e9)
     dirty = layers.paged_attention(q, kp, vp, tab, pos, backend="pallas")
     np.testing.assert_array_equal(np.asarray(clean), np.asarray(dirty))
     want = layers.paged_attention(q, kp, vp, tab, pos, backend="gather")
@@ -131,7 +131,7 @@ def test_kernel_hlo_has_no_gathered_kv():
     ``(B, P*page_size, Hkv, D)`` contiguous-KV intermediate per call;
     the pallas program must not."""
     b, width, page_size, hkv, d = 2, 4, 8, 2, 32
-    kp = jnp.zeros((16, page_size, hkv, d), jnp.float32)
+    kp = jnp.zeros((16, hkv, page_size, d), jnp.float32)
     q = jnp.zeros((b, 1, 2 * hkv, d), jnp.float32)
     tab = jnp.zeros((b, width), jnp.int32)
     pos = jnp.zeros((b,), jnp.int32)
