@@ -163,17 +163,20 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     own Hkv/tp heads) until the row-sharded output projection's
     all-reduce.
     """
-    if backend == "pallas":
-        from repro.kernels import ops as kops
-        return kops.paged_attention(q, k_pages, v_pages, page_table, pos)
-    if backend != "gather":
+    if backend not in ("gather", "pallas"):
         raise ValueError(
             f"paged_attention backend must be 'gather' or 'pallas', "
             f"got {backend!r}")
-    kv_len = jnp.asarray(pos) + 1
-    k = gather_pages(k_pages, page_table)
-    v = gather_pages(v_pages, page_table)
-    return attention_full(q, k, v, causal=True, q_offset=pos, kv_len=kv_len)
+    with jax.named_scope("attention"):
+        if backend == "pallas":
+            from repro.kernels import ops as kops
+            return kops.paged_attention(q, k_pages, v_pages, page_table,
+                                        pos)
+        kv_len = jnp.asarray(pos) + 1
+        k = gather_pages(k_pages, page_table)
+        v = gather_pages(v_pages, page_table)
+        return attention_full(q, k, v, causal=True, q_offset=pos,
+                              kv_len=kv_len)
 
 
 def _attn_block(q, k, v, qpos, kpos, scale, causal, window, m, l, acc):

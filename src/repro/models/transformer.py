@@ -82,10 +82,12 @@ def _embed(params, tokens, cfg):
 
 
 def _head(params, x, cfg):
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    w = params["embed"].T if "lm_head" not in params else params["lm_head"]
-    logits = x @ w
-    return constrain(logits, "batch", None, "vocab")
+    with jax.named_scope("head"):
+        x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+        w = params["embed"].T if "lm_head" not in params \
+            else params["lm_head"]
+        logits = x @ w
+        return constrain(logits, "batch", None, "vocab")
 
 
 def forward(params, batch, cfg, *, remat: bool = False,
@@ -180,6 +182,14 @@ def decode_step(params, cache, token, pos, cfg, *,
     dense mask) or ``"pallas"`` (fused flash-decoding kernel); see
     layers.paged_attention.
 
+    The parts of a layer run under ``jax.named_scope``s (``qkv``,
+    ``kv_write``, ``attention`` inside layers.paged_attention,
+    ``attn_out``, ``mlp``), the scan over them under ``layers`` (what
+    it adds: each layer's weights and K/V sliced out of the stacks, the
+    K/V stacked back), and the LM head under ``head``, so a profiler
+    trace finds their device ops by a stable name. Metadata only: the
+    numbers are those of the unscoped program.
+
     Returns (logits (B,1,V), new cache).
     """
     x = _embed(params, token, cfg)
@@ -197,12 +207,14 @@ def decode_step(params, cache, token, pos, cfg, *,
 
     def body(x, lp_kv):
         lp, kv = lp_kv
-        h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
-        q, k, v = attn_qkv(lp["attn"], h, cfg, positions=positions)
+        with jax.named_scope("qkv"):
+            h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
+            q, k, v = attn_qkv(lp["attn"], h, cfg, positions=positions)
         if paged:
-            kv = kvcache.write_kv_paged(kv, k, v,
-                                        cache.get("wtab", cache["ptab"]),
-                                        positions[:, 0])
+            with jax.named_scope("kv_write"):
+                kv = kvcache.write_kv_paged(
+                    kv, k, v, cache.get("wtab", cache["ptab"]),
+                    positions[:, 0])
             ctx = paged_attention(q, kv["k"], kv["v"], cache["ptab"],
                                   positions[:, 0], backend=attn_backend)
         elif use_cp:
@@ -216,15 +228,19 @@ def decode_step(params, cache, token, pos, cfg, *,
             ctx = attention(q, kv["k"], kv["v"], causal=True,
                             window=cfg.sliding_window, q_offset=pos,
                             kv_len=kv_len, kpos=kpos)
-        x = x + attn_out(lp["attn"], ctx, cfg)
-        h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.is_moe:
-            y, _ = moe_lib.moe_ffn(lp["moe"], h, cfg)
-        else:
-            y = mlp(lp["mlp"], h)
-        return x + y, kv
+        with jax.named_scope("attn_out"):
+            x = x + attn_out(lp["attn"], ctx, cfg)
+        with jax.named_scope("mlp"):
+            h = rmsnorm(x, lp["ln2"], cfg.norm_eps)
+            if cfg.is_moe:
+                y, _ = moe_lib.moe_ffn(lp["moe"], h, cfg)
+            else:
+                y = mlp(lp["mlp"], h)
+            x = x + y
+        return x, kv
 
-    x, kvs = jax.lax.scan(body, x, (params["layers"], cache["kv"]))
+    with jax.named_scope("layers"):
+        x, kvs = jax.lax.scan(body, x, (params["layers"], cache["kv"]))
     out = {"kv": kvs, "pos": pos + 1}
     if paged:
         out["ptab"] = cache["ptab"]

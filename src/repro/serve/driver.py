@@ -29,7 +29,12 @@ page somebody instead of hanging the process. This module owns that gap
     resume-by-re-prefill — instead of the stall wedging the slot table.
 
 Locking: ONE lock serializes every engine touch (steps, submits, stats
-reads). The watchdog never takes it — it reads the pre-step snapshot and
+reads). ``submit`` records how long it waited for that lock as
+``lock_wait_s`` on the request's ``submitted`` trace event, and, while
+any engine traces, enters ``serve.submit`` and ``serve.loop`` (the loop
+iteration around the engine step) as ``jax.profiler`` scopes, so a
+profiler trace shows the contention and the driver's own work between
+steps. The watchdog never takes it — it reads the pre-step snapshot and
 monotonic timestamps only, so a stalled step cannot stall its own
 detection. Cancellation is cooperative: ``abort_step`` is set by the
 watchdog; a single XLA call cannot observe it mid-flight (device calls
@@ -51,7 +56,10 @@ import logging
 import queue as _queue
 import threading
 import time
+from contextlib import nullcontext
 from typing import Dict, List, Optional
+
+from repro.serve.tracing import scope
 
 log = logging.getLogger("repro.serve")
 
@@ -172,6 +180,8 @@ class AsyncDriver:
         for e in self._engines():
             if hasattr(e, "abort_event"):
                 e.abort_event = self.abort_step
+        # step records already read into the phase histogram, per engine
+        self._phases_seen: Dict[int, int] = {}
         # previous engine-counter readings for per-step chunk telemetry
         self._prev_pf_tokens = 0
         self._prev_decode_tokens = 0
@@ -255,37 +265,50 @@ class AsyncDriver:
         request's stream closes with ``done=False, expired=True``."""
         if self._stop_evt.is_set():
             raise RuntimeError("driver is stopped")
-        t_submit = time.monotonic()
-        with self._wake:
-            if rid is None:
-                rid = self._next_rid
-            elif rid in self._streams:
-                raise ValueError(f"request {rid} already in flight")
-            self._next_rid = max(self._next_rid, rid + 1)
-            req = self._engine_submit(rid, prompt, max_new, frames=frames,
-                                      images=images, priority=priority,
-                                      deadline_s=deadline_s)
-            stream = TokenStream(rid)
-            self._streams[rid] = stream
-            self._requests[rid] = req
-            self._submit_t[rid] = t_submit
-            self.metrics.submitted.inc()
-            self._wake.notify_all()
+        with self._scope("serve.submit"):
+            t_submit = time.monotonic()
+            with self._wake:
+                lock_wait = time.monotonic() - t_submit
+                if rid is None:
+                    rid = self._next_rid
+                elif rid in self._streams:
+                    raise ValueError(f"request {rid} already in flight")
+                self._next_rid = max(self._next_rid, rid + 1)
+                eng, req = self._engine_submit(
+                    rid, prompt, max_new, frames=frames, images=images,
+                    priority=priority, deadline_s=deadline_s)
+                eng.tracer.req_amend(rid, "submitted",
+                                     lock_wait_s=lock_wait)
+                stream = TokenStream(rid)
+                self._streams[rid] = stream
+                self._requests[rid] = req
+                self._submit_t[rid] = t_submit
+                self.metrics.submitted.inc()
+                self._wake.notify_all()
         return stream
 
     def _engine_submit(self, rid, prompt, max_new, *, frames, priority,
                        images=None, deadline_s=None):
-        """Submit to either backend and return the Request record."""
+        """Submit to either backend; returns the engine that holds the
+        request and its Request record."""
         ret = self.engine.submit(rid, prompt, max_new, frames=frames,
                                  images=images, priority=priority,
                                  deadline_s=deadline_s)
         if isinstance(ret, int):       # ReplicaRouter returns the replica
-            return self.engine.engines[ret].queue[-1]
-        return ret
+            eng = self.engine.engines[ret]
+            return eng, eng.queue[-1]
+        return self.engine, ret
 
     # ----------------------------------------------------------- metrics
     def _engines(self) -> List:
         return list(getattr(self.engine, "engines", [self.engine]))
+
+    def _scope(self, name: str):
+        """A profiler scope named ``name`` while any engine traces
+        (level >= 1), else a no-op context."""
+        if any(e.tracer.enabled for e in self._engines()):
+            return scope(name)
+        return nullcontext()
 
     def stats(self) -> Dict:
         """The backend's stats dict (router: aggregated), lock-guarded."""
@@ -383,10 +406,12 @@ class AsyncDriver:
     def _step_once(self):
         """One engine step under the lock: snapshot, step (watchdog-
         timed), recover if the watchdog fired, then stream fresh tokens
-        and record latencies."""
-        self._take_snapshot()
-        occupancy = len(self._snapshot["active"])
-        self.metrics.occupancy.observe(occupancy)
+        and record latencies. The work before and after the step is a
+        ``serve.loop`` scope each."""
+        with self._scope("serve.loop"):
+            self._take_snapshot()
+            occupancy = len(self._snapshot["active"])
+            self.metrics.occupancy.observe(occupancy)
         t0 = time.monotonic()
         self._step_t0 = t0
         try:
@@ -396,20 +421,21 @@ class AsyncDriver:
                 self.engine.step()
         finally:
             self._step_t0 = None
-        now = time.monotonic()
-        self._last_step_done = now
-        self.metrics.step_latency.observe(now - t0)
-        if self._stall_fired.is_set():
-            self._recover()
-        self._observe_chunking()
-        self._observe_spec()
-        self._drain_phases()
-        self._drain_tokens(now)
-        self.metrics.queue_depth.set(
-            sum(len(e.queue) for e in self._engines()))
-        self.metrics.active_slots.set(
-            sum(sum(r is not None for r in e.active)
-                for e in self._engines()))
+        with self._scope("serve.loop"):
+            now = time.monotonic()
+            self._last_step_done = now
+            self.metrics.step_latency.observe(now - t0)
+            if self._stall_fired.is_set():
+                self._recover()
+            self._observe_chunking()
+            self._observe_spec()
+            self._observe_phases()
+            self._drain_tokens(now)
+            self.metrics.queue_depth.set(
+                sum(len(e.queue) for e in self._engines()))
+            self.metrics.active_slots.set(
+                sum(sum(r is not None for r in e.active)
+                    for e in self._engines()))
 
     def _observe_chunking(self):
         """Per-step mixed-batch telemetry: how many prefill-chunk tokens
@@ -455,16 +481,15 @@ class AsyncDriver:
                 (d["decode_tokens"] - d["prefills"])
                 / d["decode_slot_steps"])
 
-    def _drain_phases(self):
-        """Feed every engine's pending per-step phase timings into the
-        ``serve_step_phase_seconds{phase=...}`` histogram (the tracer's
-        pending deque decouples engine stepping from metric export)."""
-        for e in self._engines():
-            t = getattr(e, "tracer", None)
-            if t is None:
-                continue
-            for _sid, phases, _dur in t.drain_phases():
-                for ph, sec in phases.items():
+    def _observe_phases(self):
+        """Feed the top-level phases of every engine's step records not
+        read yet (the flight ring's newest) into the
+        ``serve_step_phase_seconds{phase=...}`` histogram."""
+        for i, e in enumerate(self._engines()):
+            n, recs = e.tracer.steps_since(self._phases_seen.get(i, 0))
+            self._phases_seen[i] = n
+            for rec in recs:
+                for ph, sec in rec.phases.items():
                     self.metrics.step_phase.observe(ph, sec)
 
     def _drain_tokens(self, now: float):

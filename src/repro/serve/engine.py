@@ -604,9 +604,10 @@ class ServeEngine:
             view["xkv"] = jax.tree.map(lambda a: a[:, slot], cache["xkv"])
         logits, out = self.model.decode_step(params, view, tokens, pos,
                                              self.cfg, **self._attn_kw)
-        tok = sample_tokens(logits[:, -1], rng=rng,
-                            temperature=self.temperature)
-        tok = jnp.where(active, tok, 0)
+        with jax.named_scope("sample"):
+            tok = sample_tokens(logits[:, -1], rng=rng,
+                                temperature=self.temperature)
+            tok = jnp.where(active, tok, 0)
         new = {"kv": out["kv"], "pos": cache["pos"],
                "ptab": cache["ptab"]}
         if "xkv" in cache:
@@ -1306,31 +1307,32 @@ class ServeEngine:
         before = self.stats["decode_tokens"]
         tr = self.tracer.begin_step(self.stats["step_count"])
         self._expire_queued(time.monotonic())
-        tr.lap("bookkeeping")
-        self._admit()
         # the legacy path prefills synchronously inside admission (its
         # own device program), so it gets its own phase label instead of
         # hiding inside bookkeeping
-        tr.lap("admit")
+        tr.enter("admit")
+        self._admit()
+        tr.enter("bookkeeping")
         if self.paged and (self.lazy or self._prefix is not None):
             self._grow_and_cow()
-        tr.lap("bookkeeping")
         mask = np.array([r is not None for r in self.active])
         if mask.any():
+            tr.enter("pack")
             if self.paged:
+                tr.sub("inputs")
                 self._sync_ptab()
-            tr.lap("pack")
+            tr.enter("dispatch")
             with self._ctx():
-                tok, self._cache = self._decode(
-                    self.params, self._cache,
-                    self._dev(self._last[:, None].astype(np.int32)),
-                    self._dev(self._pos.astype(np.int32)), self._dev(mask),
-                    self._next_rng())
-            tr.lap("dispatch")
+                tr.sub("inputs")
+                args = (self._dev(self._last[:, None].astype(np.int32)),
+                        self._dev(self._pos.astype(np.int32)),
+                        self._dev(mask), self._next_rng())
+                tr.sub("launch")
+                tok, self._cache = self._decode(self.params, self._cache,
+                                                *args)
+            toks = self._read_tokens(tok, tr)
             self.stats["decode_steps"] += 1
             self.stats["decode_slot_steps"] += int(mask.sum())
-            toks = np.asarray(tok)
-            tr.lap("sync")
             for s in range(self.slots):
                 req = self.active[s]
                 if req is None:
@@ -1353,12 +1355,25 @@ class ServeEngine:
                     self._retire(s)
         return self._finish_step(t0, before, tr)
 
+    @staticmethod
+    def _read_tokens(tok, tr) -> np.ndarray:
+        """The ``sync`` phase: wait for the step program's tokens, then
+        copy them to the host, each a child span of its own; the step
+        goes back to ``bookkeeping`` after."""
+        tr.enter("sync")
+        tr.sub("wait")
+        tok.block_until_ready()
+        tr.sub("readback")
+        toks = np.asarray(tok)
+        tr.enter("bookkeeping")
+        return toks
+
     def _finish_step(self, t0: float, before: int, tr=NULL_STEP) -> int:
         """Shared step epilogue: token count + timing telemetry, and the
         step's trace record (residual time folds into bookkeeping so the
-        phase laps partition the whole step)."""
+        phases partition the whole step)."""
         produced = self.stats["decode_tokens"] - before
-        tr.lap("bookkeeping")
+        tr.enter("bookkeeping")
         self.tracer.end_step(tr, produced)
         dt = time.perf_counter() - t0
         self.stats["step_count"] += 1
@@ -1409,7 +1424,7 @@ class ServeEngine:
         self._admit_mixed()
         if self.lazy or self._prefix is not None:
             self._grow_and_cow()
-        tr.lap("bookkeeping")
+        tr.enter("draft")
         # clear satisfied dependencies: the donor finished its prefill
         # (left _pf with full coverage) or its cursor passed the needed
         # point; a donor preempted EARLIER already cascaded (see
@@ -1424,7 +1439,7 @@ class ServeEngine:
                         if self.active[s] is not None and s not in self._pf]
         drafts = self._propose_drafts(decode_slots) \
             if self._drafter is not None else {}
-        tr.lap("draft")
+        tr.enter("pack")
         pkey = getattr(self._sched, "prefill_key", None)
         items = sorted(
             self._pf.items(),
@@ -1436,6 +1451,7 @@ class ServeEngine:
             [{"slot": s, "cursor": st["cursor"], "n": st["n"],
               "dep": st["dep"]} for s, st in items])
         if not decode_slots and not allot:
+            tr.enter("bookkeeping")
             self._admit_mixed()
             return self._finish_step(t0, before, tr)
         T = self.chunk_tokens
@@ -1489,16 +1505,16 @@ class ServeEngine:
             # the watchdog fired while admission/encode/grow ran: yield
             # at this chunk boundary instead of launching the program
             return self._finish_step(t0, before, tr)
+        tr.sub("inputs")
         self._sync_ptab()
-        tr.lap("pack")
+        tr.enter("dispatch")
         with self._ctx():
-            tok, self._cache = self._mixed(
-                self.params, self._cache, self._dev(tokens),
-                self._dev(pos), self._dev(slot_v), self._dev(active),
-                self._dev(wnull), self._next_rng())
-        tr.lap("dispatch")
-        toks = np.asarray(tok)
-        tr.lap("sync")
+            tr.sub("inputs")
+            args = (self._dev(tokens), self._dev(pos), self._dev(slot_v),
+                    self._dev(active), self._dev(wnull), self._next_rng())
+            tr.sub("launch")
+            tok, self._cache = self._mixed(self.params, self._cache, *args)
+        toks = self._read_tokens(tok, tr)
         if decode_slots:
             self.stats["decode_steps"] += 1
             self.stats["decode_slot_steps"] += len(decode_slots)

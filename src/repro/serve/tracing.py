@@ -16,12 +16,15 @@ PR 10 and deliberately dependency-free (stdlib only, like metrics.py):
     default-on — the acceptance check "span tree matches the streamed
     token count" reads ``tokens`` straight off the trace.
   * **Per-step phase records** — :meth:`Tracer.begin_step` hands the
-    engine a :class:`StepTrace` whose ``lap(phase)`` accumulates host
-    wall time between call sites (draft / pack / dispatch / sync /
-    bookkeeping...); the closed record also carries the step's work
-    items (which slot decoded/prefilled what), so a step is attributable
-    request by request. The driver drains per-step phase dicts into the
-    ``serve_step_phase_seconds{phase=...}`` histograms.
+    engine a :class:`StepTrace`; ``enter(phase)`` starts a phase (draft
+    / pack / dispatch / sync / bookkeeping...) and ends the one before,
+    so the phases partition the step's host wall time, and ``sub(name)``
+    starts a child span inside the current phase (the device inputs,
+    the launch, the wait, the readback). The closed record also carries
+    the step's work items (which slot decoded/prefilled what), so a step
+    is attributable request by request. The driver reads the newest
+    records' phases into the ``serve_step_phase_seconds{phase=...}``
+    histograms.
   * **Flight recorder** — bounded ring buffers (``deque(maxlen=N)``) of
     the last N step records and recently finished request traces.
     :meth:`Tracer.flight` snapshots them on demand; the AsyncDriver's
@@ -45,7 +48,10 @@ the shared no-op :data:`NULL_STEP`), 1 (default) keeps lifecycle events
 + step records + counters, 2 adds a per-chunk / per-decode-step event to
 the request span tree. Timestamps are ``time.perf_counter()`` — one
 monotonic clock shared by every replica in the process, so merged lanes
-line up.
+line up. At level 1 and above the step, its phases and their child
+spans are also ``jax.profiler`` host scopes named ``serve.<name>``
+(:func:`scope`, the one place this module touches jax), so a profiler
+trace shows them on the clock of the device ops.
 
 Composition: a TP engine is still ONE engine → one tracer; a DP
 ``ReplicaRouter`` gives each replica its own tracer (``tracer.replica``
@@ -68,11 +74,15 @@ LEVEL_OFF, LEVEL_BASIC, LEVEL_DETAIL = 0, 1, 2
 
 class _NullStep:
     """Shared no-op StepTrace stand-in for ``level=0`` — the engine's
-    instrumentation calls land here branch-free."""
+    instrumentation calls land here branch-free and enter no profiler
+    scope."""
 
     __slots__ = ()
 
-    def lap(self, phase: str):
+    def enter(self, phase: str):
+        pass
+
+    def sub(self, name: str):
         pass
 
     def note_decode(self, slot, rid, tokens, drafted=0, accepted=0):
@@ -90,28 +100,73 @@ class StepTrace:
     """One engine step's record under construction (engine-thread local
     until :meth:`Tracer.end_step` publishes it into the ring).
 
-    ``lap(phase)`` attributes the host time since the previous lap (or
-    ``t0``) to ``phase``, accumulating on repeats — calling it at every
-    section boundary partitions the step wall time with no gaps, which
-    is what makes the exported phase spans cover ~100% of the step span
-    (the acceptance bound is >= 95%)."""
+    The step starts in ``phase``. ``enter(phase)`` ends the current
+    phase and starts ``phase``; ``phases`` sums each phase's time over
+    its repeats, so the phases partition the step wall time with no
+    gaps — which is what makes the exported phase spans cover ~100% of
+    the step span (the acceptance bound is >= 95%). ``sub(name)`` starts
+    a child span inside the current phase, which ends at the next
+    ``sub`` or ``enter``. ``spans`` holds every phase and child span in
+    the order it ended, as ``(name, start, end, parent)``: the parent of
+    a phase is None, that of a child its phase. Each is also a profiler
+    scope (:func:`scope`): ``serve.step`` around the whole step,
+    ``serve.<phase>`` and ``serve.<child>`` inside it."""
 
-    __slots__ = ("step_id", "t0", "_t", "dur", "produced", "phases",
-                 "work")
+    __slots__ = ("step_id", "t0", "dur", "produced", "phases", "spans",
+                 "work", "_phase", "_t", "_child", "_tc", "_scopes")
 
-    def __init__(self, step_id: int):
+    def __init__(self, step_id: int, phase: str):
         self.step_id = step_id
-        self.t0 = time.perf_counter()
-        self._t = self.t0
         self.dur = 0.0
         self.produced = 0
-        self.phases: Dict[str, float] = {}    # insertion-ordered laps
+        self.phases: Dict[str, float] = {}    # insertion-ordered totals
+        self.spans: List[tuple] = []          # phase and child spans
         self.work: List[dict] = []            # per-slot items this step
+        self._child: Optional[str] = None
+        self._tc = 0.0
+        # profiler scopes: the step's, the phase's, the last child's
+        self._scopes = [_open(scope("serve.step", step_num=step_id)),
+                        _open(scope("serve." + phase)), None]
+        self.t0 = self._t = time.perf_counter()
+        self._phase = phase
 
-    def lap(self, phase: str):
+    def enter(self, phase: str):
         t = time.perf_counter()
-        self.phases[phase] = self.phases.get(phase, 0.0) + (t - self._t)
-        self._t = t
+        if self._child is not None:
+            self._end_child(t)
+        if phase == self._phase:
+            return
+        self._end_phase(t)
+        self._phase, self._t = phase, t
+        _close(self._scopes[1])
+        self._scopes[1] = _open(scope("serve." + phase))
+
+    def sub(self, name: str):
+        t = time.perf_counter()
+        if self._child is not None:
+            self._end_child(t)
+        self._child, self._tc = name, t
+        self._scopes[2] = _open(scope("serve." + name))
+
+    def _end_child(self, t: float):
+        self.spans.append((self._child, self._tc, t, self._phase))
+        self._child = None
+        _close(self._scopes[2])
+
+    def _end_phase(self, t: float):
+        ph = self._phase
+        self.phases[ph] = self.phases.get(ph, 0.0) + (t - self._t)
+        self.spans.append((ph, self._t, t, None))
+
+    def close(self):
+        """End the open child span, the current phase and the step."""
+        t = time.perf_counter()
+        if self._child is not None:
+            self._end_child(t)
+        self._end_phase(t)
+        self.dur = t - self.t0
+        _close(self._scopes[1])
+        _close(self._scopes[0])
 
     def note_decode(self, slot: int, rid: int, tokens: int,
                     drafted: int = 0, accepted: int = 0):
@@ -130,7 +185,10 @@ class StepTrace:
     def to_dict(self) -> dict:
         return {"step_id": self.step_id, "t0": self.t0, "dur": self.dur,
                 "produced": self.produced,
-                "phases": dict(self.phases), "work": list(self.work)}
+                "phases": dict(self.phases),
+                "spans": [{"name": n, "start": a, "end": b, "parent": p}
+                          for n, a, b, p in self.spans],
+                "work": list(self.work)}
 
 
 class RequestTrace:
@@ -199,9 +257,7 @@ class Tracer:
         self.steps: Deque[StepTrace] = deque(maxlen=max_steps)
         self._live: Dict[int, RequestTrace] = {}
         self._done: Deque[RequestTrace] = deque(maxlen=max_requests)
-        # per-step phase dicts awaiting the driver's histogram drain;
-        # bounded so a batch run (no driver) cannot grow it
-        self._pending: Deque[tuple] = deque(maxlen=max_steps)
+        self.published = 0      # step records ever published
         self.dropped_requests = 0
 
     @property
@@ -210,31 +266,35 @@ class Tracer:
 
     # -------------------------------------------------------- step hooks
     def begin_step(self, step_id: int):
-        """A fresh :class:`StepTrace` (or :data:`NULL_STEP` when
-        disabled) — the engine laps phases on it and hands it back to
-        :meth:`end_step`."""
+        """A fresh :class:`StepTrace` in ``bookkeeping`` (or
+        :data:`NULL_STEP` when disabled) — the engine enters phases on it
+        and hands it back to :meth:`end_step`."""
         if self.level < LEVEL_BASIC:
             return NULL_STEP
-        return StepTrace(step_id)
+        return StepTrace(step_id, "bookkeeping")
 
     def end_step(self, st, produced: int):
-        """Publish a finished StepTrace into the flight ring (and the
-        driver's pending-phases queue)."""
-        if st is NULL_STEP or self.level < LEVEL_BASIC:
+        """Close a StepTrace and publish it into the flight ring."""
+        if st is NULL_STEP:
             return
-        st.dur = time.perf_counter() - st.t0
+        st.close()
+        if self.level < LEVEL_BASIC:
+            return
         st.produced = int(produced)
         with self._lock:
             self.steps.append(st)
-            self._pending.append((st.step_id, dict(st.phases), st.dur))
+            self.published += 1
 
-    def drain_phases(self) -> List[tuple]:
-        """Pop every pending ``(step_id, phases, dur)`` triple — the
-        driver observes them into ``serve_step_phase_seconds``."""
+    def steps_since(self, seen: int):
+        """``(published, records)``: how many step records were ever
+        published, and the ring's records among those published after
+        the first ``seen`` (fewer when the ring has overwritten some) —
+        the driver's phase histograms read each step once this way."""
         with self._lock:
-            out = list(self._pending)
-            self._pending.clear()
-        return out
+            n = self.published
+            k = min(n - seen, len(self.steps))
+            recs = [self.steps[-i] for i in range(k, 0, -1)]
+        return n, recs
 
     # ----------------------------------------------------- request hooks
     def _req(self, rid: int) -> RequestTrace:
@@ -262,6 +322,22 @@ class Tracer:
             return
         with self._lock:
             self._req(rid).add(kind, fields or None)
+
+    def req_amend(self, rid: int, kind: str, **fields):
+        """Add ``fields`` to the newest ``kind`` event of live request
+        ``rid`` (level >= 1) — for a number known only once the event
+        is recorded, such as the driver's lock wait on ``submitted``."""
+        if self.level < LEVEL_BASIC:
+            return
+        with self._lock:
+            rt = self._live.get(rid)
+            if rt is None:
+                return
+            for i in range(len(rt.events) - 1, -1, -1):
+                t, k, old = rt.events[i]
+                if k == kind:
+                    rt.events[i] = (t, k, {**(old or {}), **fields})
+                    return
 
     def req_tokens(self, rid: int, n: int):
         """Count ``n`` freshly generated tokens against ``rid``."""
@@ -333,6 +409,35 @@ class Tracer:
         return export_chrome_trace(path, [self])
 
 
+# ------------------------------------------------------- profiler bridge
+# The one place this module touches jax, imported on first use so the
+# recorder stays stdlib-only. With no profiler session running a scope
+# costs about a microsecond to enter and leave.
+_SCOPE_TYPES: Optional[tuple] = None
+
+
+def scope(name: str, step_num: Optional[int] = None):
+    """A ``jax.profiler`` host scope named ``name``, not yet entered: a
+    ``StepTraceAnnotation`` when ``step_num`` is given, else a
+    ``TraceAnnotation``. Use it as a context manager."""
+    global _SCOPE_TYPES
+    if _SCOPE_TYPES is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        _SCOPE_TYPES = (TraceAnnotation, StepTraceAnnotation)
+    if step_num is None:
+        return _SCOPE_TYPES[0](name)
+    return _SCOPE_TYPES[1](name, step_num=step_num)
+
+
+def _open(sc):
+    sc.__enter__()
+    return sc
+
+
+def _close(sc):
+    sc.__exit__(None, None, None)
+
+
 # -------------------------------------------------- Chrome trace assembly
 def _us(t: float) -> float:
     """perf_counter seconds -> trace_event microseconds."""
@@ -345,7 +450,8 @@ def chrome_trace(tracers: Sequence[Tracer]) -> dict:
 
     Lanes: pid = the tracer's replica index (process rows in Perfetto),
     tid 0 = the engine-step lane — one complete ("X") span per step with
-    its phase spans nested inside — and tid ``1 + slot`` = that slot's
+    its phase spans and their child spans nested inside — and tid
+    ``1 + slot`` = that slot's
     lane, one span per step describing the decode run or prefill chunk
     the slot performed (rid + token counts in ``args``). Per-lane
     timestamps are non-decreasing (step records are ring-ordered and a
@@ -371,13 +477,14 @@ def chrome_trace(tracers: Sequence[Tracer]) -> dict:
                 "pid": pid, "tid": 0,
                 "args": {"produced": rec["produced"],
                          "phases_s": rec["phases"]}})
-            t = ts0
-            for phase, sec in rec["phases"].items():
+            for sp in sorted(rec["spans"], key=lambda sp: sp["start"]):
+                child = sp["parent"] is not None
                 events.append({
-                    "name": phase, "cat": "phase", "ph": "X",
-                    "ts": t, "dur": _us(sec), "pid": pid, "tid": 0,
-                    "args": {}})
-                t += _us(sec)
+                    "name": sp["name"], "cat": "span" if child else "phase",
+                    "ph": "X", "ts": _us(sp["start"]),
+                    "dur": _us(sp["end"] - sp["start"]), "pid": pid,
+                    "tid": 0,
+                    "args": {"parent": sp["parent"]} if child else {}})
             for item in rec["work"]:
                 s = item["slot"]
                 slots_seen.add(s)
@@ -417,8 +524,8 @@ def export_chrome_trace(path: str, tracers: Sequence[Tracer]) -> dict:
 
 
 def phase_coverage(tracers: Sequence[Tracer]) -> float:
-    """Fraction of recorded step wall time the phase laps account for —
-    1.0 when every section between begin_step and end_step was lapped
+    """Fraction of recorded step wall time the phases account for —
+    1.0 when every section between begin_step and end_step was in one
     (the acceptance bound is >= 0.95). NaN-free: 1.0 with no steps."""
     tot = cov = 0.0
     for tr in tracers:

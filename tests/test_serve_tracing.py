@@ -9,6 +9,7 @@ the lock-free ``/healthz`` + ``/debug/*`` endpoints answering while a
 stalled step holds the driver lock.
 """
 import json
+import re
 import threading
 import time
 import urllib.request
@@ -52,20 +53,31 @@ def _run_engine(eng, prompts, max_new=6):
 # ------------------------------------------------------------- unit level
 
 def test_step_trace_laps_partition_wall_time():
-    st = StepTrace(7)
+    st = StepTrace(7, "pack")
     time.sleep(0.01)
-    st.lap("pack")
+    st.enter("dispatch")
+    st.sub("launch")
     time.sleep(0.01)
-    st.lap("dispatch")
-    st.lap("pack")                      # repeats accumulate
+    st.enter("pack")                    # repeats accumulate
+    st.enter("pack")                    # re-entering the current phase
     tr = Tracer()
     tr.end_step(st, produced=3)
     [rec] = tr.flight()["steps"]
     assert rec["step_id"] == 7 and rec["produced"] == 3
-    assert set(rec["phases"]) == {"pack", "dispatch"}
-    # laps partition [t0, end_step): coverage is ~100% of dur
+    assert list(rec["phases"]) == ["pack", "dispatch"]
+    assert rec["phases"]["pack"] >= 0.01
+    assert rec["phases"]["dispatch"] >= 0.01
+    # phases partition [t0, end_step): coverage is ~100% of dur
     assert sum(rec["phases"].values()) <= rec["dur"]
     assert sum(rec["phases"].values()) >= 0.95 * rec["dur"]
+    # one span per phase entry, and the child inside its phase
+    names = [(sp["name"], sp["parent"]) for sp in rec["spans"]]
+    assert names == [("pack", None), ("launch", "dispatch"),
+                     ("dispatch", None), ("pack", None)]
+    [launch] = [sp for sp in rec["spans"] if sp["name"] == "launch"]
+    [disp] = [sp for sp in rec["spans"] if sp["name"] == "dispatch"]
+    assert disp["start"] <= launch["start"] <= launch["end"] \
+        <= disp["end"]
 
 
 def test_tracer_rings_are_bounded():
@@ -82,16 +94,21 @@ def test_tracer_rings_are_bounded():
     assert len(snap["finished_requests"]) == 3     # ring, newest kept
     assert snap["finished_requests"][-1]["rid"] == 8
     assert all(r["dropped_events"] > 0 for r in snap["finished_requests"])
-    # pending phase queue drains once, then is empty
-    assert len(tr.drain_phases()) == 4
-    assert tr.drain_phases() == []
+    # every step is published once; the ring keeps the newest 4
+    assert tr.published == 10
+    n, recs = tr.steps_since(7)
+    assert n == 10 and [r.step_id for r in recs] == [7, 8, 9]
+    n, recs = tr.steps_since(0)
+    assert [r.step_id for r in recs] == [6, 7, 8, 9]
+    assert tr.steps_since(10) == (10, [])
 
 
 def test_level0_is_off_and_null_step_is_shared():
     tr = Tracer(level=LEVEL_OFF)
     assert not tr.enabled
     assert tr.begin_step(0) is NULL_STEP
-    NULL_STEP.lap("x")
+    NULL_STEP.enter("x")
+    NULL_STEP.sub("y")
     NULL_STEP.note_decode(0, 0, 1)
     NULL_STEP.note_chunk(0, 0, 0, 4)   # all no-ops
     tr.end_step(NULL_STEP, produced=5)
@@ -441,3 +458,167 @@ def test_tracing_overhead_within_bounds():
     assert out_on == out_off
     # generous CI-safe envelope; the real 5% bound rides on the bench
     assert t_on < 3.0 * t_off + 0.25, (t_on, t_off)
+
+
+# ------------------------------------- spans on the profiler's clock
+
+def _children(rec, name):
+    return [sp for sp in rec["spans"] if sp["name"] == name]
+
+
+def _assert_nested(rec, child, parent):
+    """Every ``child`` span of the step names ``parent`` and lies inside
+    one ``parent`` phase span of the same step."""
+    phases = [sp for sp in rec["spans"]
+              if sp["name"] == parent and sp["parent"] is None]
+    kids = _children(rec, child)
+    assert kids, (child, rec["spans"])
+    for sp in kids:
+        if sp["parent"] != parent:
+            continue
+        assert any(ph["start"] <= sp["start"] <= sp["end"] <= ph["end"]
+                   for ph in phases), (sp, phases)
+    return [sp for sp in kids if sp["parent"] == parent]
+
+
+@pytest.mark.parametrize("mixed", [True, False])
+def test_step_child_spans_nest_in_dispatch_and_sync(mixed):
+    params = _params(CFG)
+    prompts = _prompts(np.random.default_rng(10), CFG, (30, 5, 12))
+    eng = ServeEngine(CFG, params, slots=2, max_len=64, paged=True,
+                      mixed=mixed, chunk_tokens=16)
+    _run_engine(eng, prompts, max_new=6)
+    assert phase_coverage([eng.tracer]) >= 0.95
+    vocab = {"bookkeeping", "draft", "pack", "dispatch", "sync"} if mixed \
+        else {"bookkeeping", "admit", "pack", "dispatch", "sync"}
+    ran = 0
+    for rec in eng.tracer.flight()["steps"]:
+        assert set(rec["phases"]) <= vocab
+        # the phase spans are the phases dict, entry by entry
+        tops = [sp for sp in rec["spans"] if sp["parent"] is None]
+        for ph, sec in rec["phases"].items():
+            got = sum(sp["end"] - sp["start"] for sp in tops
+                      if sp["name"] == ph)
+            assert got == pytest.approx(sec, rel=1e-9, abs=1e-12)
+        for sp in rec["spans"]:
+            assert rec["t0"] <= sp["start"] <= sp["end"] \
+                <= rec["t0"] + rec["dur"] + 1e-9
+        if "dispatch" not in rec["phases"]:
+            continue
+        ran += 1
+        assert len(_assert_nested(rec, "inputs", "dispatch")) == 1
+        assert len(_assert_nested(rec, "launch", "dispatch")) == 1
+        assert len(_assert_nested(rec, "wait", "sync")) == 1
+        assert len(_assert_nested(rec, "readback", "sync")) == 1
+        # the page-table push is an input too, inside pack
+        for sp in _children(rec, "inputs"):
+            assert sp["parent"] in ("pack", "dispatch")
+            if sp["parent"] == "pack":
+                _assert_nested(rec, "inputs", "pack")
+        kids = sum(sp["end"] - sp["start"] for sp in rec["spans"]
+                   if sp["parent"] == "dispatch")
+        assert kids <= rec["phases"]["dispatch"] + 1e-9
+    assert ran >= 3
+
+
+def test_profiler_trace_holds_serve_spans(tmp_path):
+    """Two engine steps under a jax.profiler trace on the CPU: the
+    step, its phases and child spans are host events named serve.*."""
+    from jax.profiler import ProfileData
+    params = _params(CFG)
+    prompts = _prompts(np.random.default_rng(11), CFG, (20, 6))
+    eng = ServeEngine(CFG, params, slots=2, max_len=64, paged=True,
+                      mixed=True, chunk_tokens=16)
+    for i, p in enumerate(prompts):
+        eng.submit(i, p, max_new=4)
+    eng.step()                          # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.step()
+        eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    [path] = list(tmp_path.rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(path))
+    names = {e.name for plane in pd.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"serve.step", "serve.pack", "serve.dispatch", "serve.inputs",
+            "serve.launch", "serve.sync", "serve.wait",
+            "serve.readback"} <= names, sorted(
+                n for n in names if n.startswith("serve"))
+
+
+def test_mixed_fn_carries_named_scopes():
+    """Each named scope of the serve decode path reaches the op_name
+    metadata of the lowered step program."""
+    params = _params(CFG)
+    eng = ServeEngine(CFG, params, slots=2, max_len=64, paged=True,
+                      mixed=True, chunk_tokens=16)
+    eng.submit(0, np.arange(5, dtype=np.int32), max_new=2)
+    eng.run()
+    T = eng.chunk_tokens
+    zeros = np.zeros(T, np.int32)
+    lowered = eng._mixed.lower(
+        eng.params, eng._cache, np.zeros((T, 1), np.int32), zeros, zeros,
+        np.ones(T, bool), np.ones(T, bool), None)
+    op_names = set(re.findall(r'op_name="([^"]*)"',
+                              lowered.compile().as_text()))
+    for name in ("layers", "qkv", "kv_write", "attention", "attn_out", "mlp",
+                 "head", "sample"):
+        assert any(f"/{name}/" in n or n.endswith(f"/{name}")
+                   for n in op_names), name
+
+
+def test_driver_records_lock_wait_on_submitted_event():
+    params = _params(CFG)
+    eng = ServeEngine(CFG, params, slots=2, max_len=64, paged=True,
+                      mixed=True, chunk_tokens=16)
+    drv = AsyncDriver(eng, start=False)
+    held, done = threading.Event(), threading.Event()
+
+    def hold():
+        with drv._lock:
+            held.set()
+            time.sleep(0.2)
+        done.set()
+
+    t = threading.Thread(target=hold, daemon=True)
+    t.start()
+    assert held.wait(timeout=10.0)
+    drv.submit(np.arange(5, dtype=np.int32), max_new=2, rid=0)
+    assert done.is_set()
+    t.join(timeout=10.0)
+    assert not t.is_alive()
+    drv.submit(np.arange(4, dtype=np.int32), max_new=2, rid=1)
+    waits = {}
+    for rid in (0, 1):
+        sub = next(e for e in eng.tracer.request_trace(rid)["events"]
+                   if e["kind"] == "submitted")
+        assert sub["prompt_tokens"] == 5 - rid   # the engine's fields stay
+        waits[rid] = sub["lock_wait_s"]
+    assert waits[0] >= 0.15, waits
+    assert 0.0 <= waits[1] < 0.15, waits
+    drv.stop(drain=False)
+
+
+def test_trace_levels_give_identical_tokens():
+    from repro.serve.speculative import SpecConfig
+    params = _params(CFG)
+    rng = np.random.default_rng(12)
+    motif = rng.integers(0, CFG.vocab_size, size=(4,))
+    prompts = _prompts(rng, CFG, (26, 7)) + \
+        [np.tile(motif, 5).astype(np.int32)]
+    outs = {}
+    for level in (0, 1, 2):
+        for kind, kw in (("mixed", {"mixed": True, "chunk_tokens": 16}),
+                         ("spec", {"mixed": True, "chunk_tokens": 16,
+                                   "spec": SpecConfig(k=3)}),
+                         ("legacy", {"mixed": False})):
+            eng = ServeEngine(CFG, params, slots=2, max_len=64, paged=True,
+                              trace_level=level, **kw)
+            res = _run_engine(eng, prompts, max_new=5)
+            outs[level, kind] = {r: list(v.out) for r, v in res.items()}
+    for kind in ("mixed", "spec", "legacy"):
+        assert outs[0, kind] == outs[1, kind] == outs[2, kind], kind
+    assert outs[0, "mixed"] == outs[0, "spec"] == outs[0, "legacy"]
