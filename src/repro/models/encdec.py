@@ -165,9 +165,10 @@ def decode_step(params, cache, token, pos, cfg, *,
     is the FIXED encoder context, so paging it would buy nothing. An
     optional ``"wtab"`` write table redirects the KV scatter only (the
     mixed token-slot step's shared-prefix recompute path — see the dense
-    transformer's decode_step). ``attn_backend`` picks the paged
-    self-attention path: ``"gather"`` or the fused ``"pallas"`` kernel
-    (layers.paged_attention).
+    transformer's decode_step), and an optional ``"rows"`` live-row
+    count cuts the paged self-attention to the live rows (likewise).
+    ``attn_backend`` picks the paged self-attention path: ``"gather"``
+    or the fused ``"pallas"`` kernel (layers.paged_attention).
     """
     x = params["tok_embed"][token].astype(jnp.dtype(cfg.dtype))
     paged = "ptab" in cache
@@ -192,7 +193,8 @@ def decode_step(params, cache, token, pos, cfg, *,
                                         cache.get("wtab", cache["ptab"]),
                                         positions)
             ctx = paged_attention(q, kv["k"], kv["v"], cache["ptab"],
-                                  positions, backend=attn_backend)
+                                  positions, backend=attn_backend,
+                                  rows=cache.get("rows"))
         else:
             kv = kvcache.write_kv(kv, k, v, pos)
             ctx = attention(q, kv["k"], kv["v"], causal=True, q_offset=pos,

@@ -147,10 +147,17 @@ def copy_page(pool, src, dst):
 def gather_pages(pool, page_table):
     """pool: (n_pages, Hkv, page_size, D); page_table: (B, P) ->
     contiguous per-row KV (B, P * page_size, Hkv, D) in logical position
-    order."""
+    order.
+
+    The gather indexes the pool's token-major view (n_pages, page_size,
+    Hkv, D), so its output is already in position order. On the TPU the
+    compiler lays a layer's pool out token-major for the per-token K/V
+    write, which makes the view free: the compiled mixed step transposes
+    neither the gathered pages nor, inside the live-row switch of
+    layers.paged_attention, the pool."""
     b, p = page_table.shape
     _, hkv, page_size, d = pool.shape
-    out = pool[page_table].swapaxes(2, 3)       # (B, P, page_size, Hkv, D)
+    out = pool.swapaxes(1, 2)[page_table]       # (B, P, page_size, Hkv, D)
     return out.reshape(b, p * page_size, hkv, d)
 
 

@@ -123,8 +123,26 @@ def attention_full(q, k, v, *, causal: bool, window: int = 0,
     return out.reshape(b, s, hq, d)
 
 
+def row_widths(t: int) -> tuple:
+    """The static ladder of row counts a ``rows``-cut paged attention can
+    run at for a batch of ``t`` rows: powers of two from 8 up to ``t``,
+    with ``t`` itself on top (8, 16, ..., 256 for ``t`` = 256; (4,) for
+    ``t`` = 4)."""
+    widths, w = [], 8
+    while w < t:
+        widths.append(w)
+        w *= 2
+    return tuple(widths) + (t,)
+
+
+def row_width(rows: int, t: int) -> int:
+    """The ladder's smallest width holding ``rows`` live rows (host side:
+    the width :func:`paged_attention` runs for that count)."""
+    return next(w for w in row_widths(t) if w >= rows)
+
+
 def paged_attention(q, k_pages, v_pages, page_table, pos, *,
-                    backend: str = "gather"):
+                    backend: str = "gather", rows=None):
     """Decode-time block-table attention over a paged KV pool (vLLM-style).
 
     q: (B, 1, Hq, D) — one fresh token per slot-table row.
@@ -133,6 +151,14 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     page_table: (B, P) pool indices per row (0 = null page).
     pos: (B,) per-row cursors (tokens already in context, incl. this one's
     write — the query attends to positions [0, pos]).
+    rows: optional traced int32 scalar, the live-row contract: rows
+    ``[0, rows)`` are live and rows ``[rows, B)`` are pads whose output
+    nobody reads. The attention then runs only over the first ``Tb``
+    rows, ``Tb`` the smallest width of :func:`row_widths` (B) holding
+    ``rows``, one ``lax.switch`` branch per width, and output rows
+    ``[rows, B)`` are zeros. Shapes stay static, so this adds no trace;
+    the widest branch is the uncut computation. ``None`` (default) runs
+    every row.
 
     Two backends, token-identical greedy outputs:
 
@@ -161,13 +187,14 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
     over "model" (core/sharding.cache_pspecs) — both backends index the
     unsharded page axis and stay head-local per device (each sees its
     own Hkv/tp heads) until the row-sharded output projection's
-    all-reduce.
+    all-reduce; the row cut slices the replicated batch axis only.
     """
     if backend not in ("gather", "pallas"):
         raise ValueError(
             f"paged_attention backend must be 'gather' or 'pallas', "
             f"got {backend!r}")
-    with jax.named_scope("attention"):
+
+    def attend(q, k_pages, v_pages, page_table, pos):
         if backend == "pallas":
             from repro.kernels import ops as kops
             return kops.paged_attention(q, k_pages, v_pages, page_table,
@@ -177,6 +204,27 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *,
         v = gather_pages(v_pages, page_table)
         return attention_full(q, k, v, causal=True, q_offset=pos,
                               kv_len=kv_len)
+
+    def cut(w):
+        if w == b:
+            return attend
+
+        def branch(q, k_pages, v_pages, page_table, pos):
+            out = attend(q[:w], k_pages, v_pages, page_table[:w], pos[:w])
+            return jnp.pad(out, ((0, b - w), (0, 0), (0, 0), (0, 0)))
+        return branch
+
+    with jax.named_scope("attention"):
+        if rows is None:
+            return attend(q, k_pages, v_pages, page_table, pos)
+        b = q.shape[0]
+        widths = row_widths(b)
+        # index of the smallest width >= rows (the top one past them all)
+        idx = jnp.sum(jnp.asarray(widths[:-1]) < rows)
+        out = jax.lax.switch(idx, [cut(w) for w in widths], q, k_pages,
+                             v_pages, page_table, pos)
+        live = jnp.arange(b) < rows
+        return jnp.where(live[:, None, None, None], out, 0)
 
 
 def _attn_block(q, k, v, qpos, kpos, scale, causal, window, m, l, acc):

@@ -177,10 +177,12 @@ def decode_step(params, cache, token, pos, cfg, *,
     SCATTER only (attention still gathers through ``ptab``) — the mixed
     token-slot step uses it to recompute positions whose KV already
     lives in shared prefix pages without rewriting pages other slots
-    read (rows redirected to the null page 0). ``attn_backend`` picks
-    the paged-attention execution path — ``"gather"`` (XLA gather +
-    dense mask) or ``"pallas"`` (fused flash-decoding kernel); see
-    layers.paged_attention.
+    read (rows redirected to the null page 0). An optional ``"rows"``
+    scalar is the live-row count: rows past it are pads, and the paged
+    attention runs over the live rows only (layers.paged_attention's
+    ``rows``). ``attn_backend`` picks the paged-attention execution path
+    — ``"gather"`` (XLA gather + dense mask) or ``"pallas"`` (fused
+    flash-decoding kernel); see layers.paged_attention.
 
     The parts of a layer run under ``jax.named_scope``s (``qkv``,
     ``kv_write``, ``attention`` inside layers.paged_attention,
@@ -216,7 +218,8 @@ def decode_step(params, cache, token, pos, cfg, *,
                     kv, k, v, cache.get("wtab", cache["ptab"]),
                     positions[:, 0])
             ctx = paged_attention(q, kv["k"], kv["v"], cache["ptab"],
-                                  positions[:, 0], backend=attn_backend)
+                                  positions[:, 0], backend=attn_backend,
+                                  rows=cache.get("rows"))
         elif use_cp:
             # context-parallel: shard-local write + psum-softmax combine
             ctx, kv = cp_decode_attention(q, kv, k, v, pos,

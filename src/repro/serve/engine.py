@@ -129,7 +129,14 @@ worst-case reservation pre-booked so lazy growth never re-buckets
 mid-decode (serve/step.page_bucket, ``_sync_ptab``). The paged-attention
 gather then reads ``bucket * page_size`` positions per row instead of
 the full ``max_len`` table width, and the program retraces only when an
-admission pushes the high-water across a bucket boundary.
+admission pushes the high-water across a bucket boundary. Cost tracks
+the LIVE rows too: the mixed step's pads (every row of the budget past
+the last live one) skip the attention, which runs over the smallest
+width of a static ladder (8, 16, ..., ``chunk_tokens``) holding the
+live rows — one ``lax.switch`` branch per width inside the same
+program, so no width adds a trace (layers.paged_attention's ``rows``;
+``stats["attn_steps_w<width>"]`` counts the steps each width ran). The
+pads' matmuls and LM head still run over the whole budget.
 
 ``engine.stats`` counts device calls AND traces (``decode_traces`` /
 ``prefill_traces`` increment only while tracing), so tests can assert the
@@ -161,6 +168,7 @@ from repro.core import sharding as shd
 from repro.core.pspec import sharding_rules
 from repro.core.strategy import Strategy
 from repro.models import get_model, kvcache
+from repro.models.layers import row_width, row_widths
 from repro.serve.paging import PageAllocator, pages_for
 from repro.serve.prefix import PrefixCache
 from repro.serve.sampling import sample_tokens
@@ -475,6 +483,10 @@ class ServeEngine:
         self.abort_event = None
         if self.mixed:
             self._mixed = jax.jit(self._mixed_fn, donate_argnums=(1,))
+            # steps whose paged attention ran at each width of the
+            # live-row ladder (layers.row_widths)
+            for w in row_widths(self.chunk_tokens):
+                self.stats[f"attn_steps_w{w}"] = 0
             if cfg.arch_type == "audio":
                 self._encode = jax.jit(self._encode_fn,
                                        donate_argnums=(1,))
@@ -586,6 +598,14 @@ class ServeEngine:
         this row's KV write to the null page: the position's KV already
         lives in shared prefix pages, or the row is padding).
 
+        Live rows: a row that samples or writes K/V is live; one that
+        does neither is a pad or a recompute of prefix-covered positions,
+        and nothing reads its output. The packer puts every live row
+        before the pads, so the rows past the last live one go to
+        ``decode_step`` as ``rows`` and the paged attention skips them
+        (layers.paged_attention's live-row contract) — derived here from
+        ``active`` and ``wnull``, so it costs no input of its own.
+
         Exactness: ``decode_step`` scatters EVERY row's K/V per layer
         before the paged attention gathers, so a chunk's tokens attend to
         each other (and to a same-program donor's chunk) exactly as the
@@ -597,9 +617,12 @@ class ServeEngine:
         speculative decode (ROADMAP #2) widens columns, not the design.
         """
         self.stats["decode_traces"] += 1    # Python side effect: trace-time only
+        T = tokens.shape[0]
         ptab_rows = cache["ptab"][slot]               # (T, table_width)
+        live = active | ~wnull
         view = {"kv": cache["kv"], "ptab": ptab_rows,
-                "wtab": jnp.where(wnull[:, None], 0, ptab_rows)}
+                "wtab": jnp.where(wnull[:, None], 0, ptab_rows),
+                "rows": jnp.max(jnp.where(live, jnp.arange(1, T + 1), 0))}
         if "xkv" in cache:
             view["xkv"] = jax.tree.map(lambda a: a[:, slot], cache["xkv"])
         logits, out = self.model.decode_step(params, view, tokens, pos,
@@ -1505,6 +1528,12 @@ class ServeEngine:
             # the watchdog fired while admission/encode/grow ran: yield
             # at this chunk boundary instead of launching the program
             return self._finish_step(t0, before, tr)
+        # the live-row count _mixed_fn derives, and the attention width
+        live = np.flatnonzero(active | ~wnull)
+        n_live = int(live[-1]) + 1 if live.size else 0
+        width = row_width(n_live, T)
+        self.stats[f"attn_steps_w{width}"] += 1
+        tr.note_rows(n_live, width, T)
         tr.sub("inputs")
         self._sync_ptab()
         tr.enter("dispatch")

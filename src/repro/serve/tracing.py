@@ -22,7 +22,9 @@ PR 10 and deliberately dependency-free (stdlib only, like metrics.py):
     starts a child span inside the current phase (the device inputs,
     the launch, the wait, the readback). The closed record also carries
     the step's work items (which slot decoded/prefilled what), so a step
-    is attributable request by request. The driver reads the newest
+    is attributable request by request, and for a mixed step its live
+    rows, the paged attention's row width and the token budget
+    (``rows``, ``attn_rows``, ``budget``). The driver reads the newest
     records' phases into the ``serve_step_phase_seconds{phase=...}``
     histograms.
   * **Flight recorder** — bounded ring buffers (``deque(maxlen=N)``) of
@@ -91,6 +93,9 @@ class _NullStep:
     def note_chunk(self, slot, rid, start, count):
         pass
 
+    def note_rows(self, rows, attn_rows, budget):
+        pass
+
 
 #: the singleton every disabled begin_step returns
 NULL_STEP = _NullStep()
@@ -113,7 +118,8 @@ class StepTrace:
     ``serve.<phase>`` and ``serve.<child>`` inside it."""
 
     __slots__ = ("step_id", "t0", "dur", "produced", "phases", "spans",
-                 "work", "_phase", "_t", "_child", "_tc", "_scopes")
+                 "work", "rows", "attn_rows", "budget", "_phase", "_t",
+                 "_child", "_tc", "_scopes")
 
     def __init__(self, step_id: int, phase: str):
         self.step_id = step_id
@@ -122,6 +128,9 @@ class StepTrace:
         self.phases: Dict[str, float] = {}    # insertion-ordered totals
         self.spans: List[tuple] = []          # phase and child spans
         self.work: List[dict] = []            # per-slot items this step
+        # the mixed step's live rows, the attention width that ran and
+        # the token budget (note_rows); None where no program ran
+        self.rows = self.attn_rows = self.budget = None
         self._child: Optional[str] = None
         self._tc = 0.0
         # profiler scopes: the step's, the phase's, the last child's
@@ -182,13 +191,17 @@ class StepTrace:
                           "rid": int(rid), "start": int(start),
                           "count": int(count)})
 
+    def note_rows(self, rows: int, attn_rows: int, budget: int):
+        self.rows, self.attn_rows, self.budget = rows, attn_rows, budget
+
     def to_dict(self) -> dict:
         return {"step_id": self.step_id, "t0": self.t0, "dur": self.dur,
                 "produced": self.produced,
                 "phases": dict(self.phases),
                 "spans": [{"name": n, "start": a, "end": b, "parent": p}
                           for n, a, b, p in self.spans],
-                "work": list(self.work)}
+                "work": list(self.work), "rows": self.rows,
+                "attn_rows": self.attn_rows, "budget": self.budget}
 
 
 class RequestTrace:
